@@ -5,7 +5,7 @@ mix is a ``jnp.roll`` over the full node axis or a dense ``[m, m]`` matmul,
 and whether that turns into degree-many neighbor messages or an all-gather of
 the whole stacked payload is left to GSPMD's sharding propagation.  This
 module makes the wire model explicit: ``choco_round_ppermute`` runs the same
-CHOCO round under ``jax.experimental.shard_map`` over the mesh's node axes,
+CHOCO round under ``jax.shard_map`` over the mesh's node axes,
 where each device holds a contiguous block of nodes and *only compressed
 payloads travel between actual graph neighbors* via ``jax.lax.ppermute``:
 
@@ -45,7 +45,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.compression import Compressor, Identity
@@ -884,9 +883,9 @@ def choco_round_ppermute_lanes(
         return tuple(out_t), tuple(out_s)
 
     out_specs = ((P(axes),) * n_lanes, (P(axes),) * n_lanes)
-    fn = shard_map(
-        body, mesh, in_specs=tuple(specs), out_specs=out_specs,
-        check_rep=False,
+    fn = jax.shard_map(
+        body, mesh=mesh, in_specs=tuple(specs), out_specs=out_specs,
+        check_vma=False,
     )
     return fn(*args)
 
@@ -1050,7 +1049,9 @@ def mix_stacked_ppermute(tree, topology: Topology, *, mesh, node_axes="data",
                 lambda x: _mix_local(x, plan, axes, ndev, block, idx), t
             )
 
-        return shard_map(body, mesh, in_specs=P(axes), out_specs=P(axes), check_rep=False)(tree)
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=P(axes), out_specs=P(axes), check_vma=False
+        )(tree)
 
     from repro.core.wire import compile_union_wire
 
@@ -1136,8 +1137,9 @@ def mix_stacked_ppermute(tree, topology: Topology, *, mesh, node_axes="data",
         return (mixed, bits) if faulted else mixed
 
     out_specs = (P(axes), P(axes)) if faulted else P(axes)
-    return shard_map(
-        body_tv, mesh, in_specs=tuple(specs), out_specs=out_specs, check_rep=False
+    return jax.shard_map(
+        body_tv, mesh=mesh, in_specs=tuple(specs), out_specs=out_specs,
+        check_vma=False,
     )(*args)
 
 
@@ -1204,8 +1206,9 @@ def server_average_ppermute(tree, sampled, *, mesh, node_axes="data"):
 
         return jax.tree.map(avg, t)
 
-    return shard_map(
-        body, mesh, in_specs=(P(axes), P(axes)), out_specs=P(), check_rep=False
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(P(axes), P(axes)), out_specs=P(),
+        check_vma=False,
     )(tree, sampled)
 
 

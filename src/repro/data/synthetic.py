@@ -237,11 +237,9 @@ def node_token_stream(
     probs = ranks ** (-zipf_a)
     probs /= probs.sum()
     perms = np.stack([rng.permutation(vocab_size) for _ in range(num_nodes)])
+    node = np.arange(num_nodes)[:, None, None]
     while True:
         base = rng.choice(vocab_size, size=(num_nodes, batch_per_node, seq_len), p=probs)
-        tokens = np.take_along_axis(
-            perms[:, None, None, :].repeat(batch_per_node, 1).repeat(seq_len, 2),
-            base[..., None],
-            axis=-1,
-        )[..., 0]
-        yield tokens.astype(np.int32)
+        # index each node's permutation directly: host memory is O(m b S),
+        # independent of the vocabulary
+        yield perms[node, base].astype(np.int32)

@@ -20,8 +20,8 @@ live key in *any* visited block would emit a spurious uniform average
 rather than the reference's all-masked softmax.  ``from_bitmap`` checks.
 
 Like ``flash_attention.py``, whole K/V rides in VMEM per (bh, q-block)
-grid cell — fine at training sequence lengths; the index lists are small
-int32 rows mapped per q block via their own BlockSpecs.
+grid cell — fine at training sequence lengths; the index lists are
+scalar-prefetch operands held in SMEM, read per q block by the kernel.
 """
 from __future__ import annotations
 
@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention import NEG_INF
 
@@ -135,24 +136,20 @@ class BlockSparsePattern:
 
 
 def _block_sparse_kernel(idx_ref, state_ref, cnt_ref, q_ref, k_ref, v_ref,
-                         o_ref, *, scale, causal, window, block_q, block_k):
+                         o_ref, *, scale, causal, window, block_q, block_k,
+                         width):
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * scale  # [block_q, hd]
     hd = q.shape[-1]
-    count = cnt_ref[0, 0]
+    count = cnt_ref[qi]
 
     def body(j, carry):
         acc, m_prev, l_prev = carry
-        kb = idx_ref[0, j]
-        st = state_ref[0, j]
-        k = pl.load(
-            k_ref, (pl.dslice(0, 1), pl.dslice(kb * block_k, block_k),
-                    pl.dslice(0, hd))
-        )[0].astype(jnp.float32)
-        v = pl.load(
-            v_ref, (pl.dslice(0, 1), pl.dslice(kb * block_k, block_k),
-                    pl.dslice(0, hd))
-        )[0].astype(jnp.float32)
+        kb = idx_ref[qi * width + j]
+        st = state_ref[qi * width + j]
+        kv_rows = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
+        k = k_ref[0, kv_rows, :].astype(jnp.float32)
+        v = v_ref[0, kv_rows, :].astype(jnp.float32)
         s = q @ k.T
 
         q_pos = qi * block_q + jax.lax.broadcasted_iota(
@@ -202,7 +199,6 @@ def block_sparse_attention_pallas(
     idx, state, counts, width = pattern.compact()
     nq = sq // block_q
 
-    row = lambda b, i: (i, 0)  # noqa: E731 — per-q-block index rows
     kernel = functools.partial(
         _block_sparse_kernel,
         scale=scale,
@@ -210,25 +206,29 @@ def block_sparse_attention_pallas(
         window=pattern.window,
         block_q=block_q,
         block_k=block_k,
+        width=width,
+    )
+    # the index lists ride in SMEM as scalar-prefetch operands (flattened:
+    # 1-D SMEM arrays pad least); every index map also receives them
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(bh, nq),
+        in_specs=[
+            pl.BlockSpec((1, block_q, hd), lambda b, i, *_: (b, i, 0)),
+            pl.BlockSpec((1, sk, hd), lambda b, i, *_: (b, 0, 0)),
+            pl.BlockSpec((1, sk, hd), lambda b, i, *_: (b, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, hd), lambda b, i, *_: (b, i, 0)),
     )
     return pl.pallas_call(
         kernel,
-        grid=(bh, nq),
-        in_specs=[
-            pl.BlockSpec((1, width), row),
-            pl.BlockSpec((1, width), row),
-            pl.BlockSpec((1, 1), row),
-            pl.BlockSpec((1, block_q, hd), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, sk, hd), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, sk, hd), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, hd), lambda b, i: (b, i, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq, hd), q.dtype),
         interpret=interpret,
     )(
-        jnp.asarray(idx),
-        jnp.asarray(state),
-        jnp.asarray(counts.reshape(nq, 1)),
+        jnp.asarray(idx.reshape(-1)),
+        jnp.asarray(state.reshape(-1)),
+        jnp.asarray(counts),
         q,
         k,
         v,

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.kernels.ref import LANES, tau_for
+from repro.kernels.ref import LANES, ordered_norm, pack_bits, tau_for, unpack_bits
 
 # target total VMEM footprint per grid step (inputs + outputs + accumulator)
 VMEM_BUDGET = 8 << 20
@@ -84,25 +84,18 @@ def _fused_encode_kernel(enc_ref, deq_ref, tn_ref, hat_ref, xi_ref,
     ``hat_new`` accumulates in an extra [m, 128] output whose constant index
     map revisits the same tile every grid step (TPU grids are sequential, so
     the read-modify-write accumulation is well-defined)."""
-    pack = 8 // bits
     maxlvl = (1 << bits) - 1
 
     hat = hat_ref[...]
     resid = (tn_ref[...] - hat).astype(jnp.float32)
-    m, rows, _ = resid.shape
 
     # stochastic round (same arithmetic as quantize_ref, bit-identical)
     q = jnp.floor(jnp.abs(resid) * enc_ref[...][:, None, :] + xi_ref[...])
     lvlf = jnp.clip(q, 0, maxlvl)
     neg = resid < 0
 
-    l = lvlf.astype(jnp.uint32).reshape(m, rows // pack, pack, LANES)
-    shifts = (jnp.arange(pack, dtype=jnp.uint32) * bits).reshape(1, 1, pack, 1)
-    lvl_ref[...] = (l << shifts).sum(axis=2).astype(jnp.uint8)
-
-    s = neg.astype(jnp.uint32).reshape(m, rows // 8, 8, LANES)
-    sshift = jnp.arange(8, dtype=jnp.uint32).reshape(1, 1, 8, 1)
-    sign_ref[...] = (s << sshift).sum(axis=2).astype(jnp.uint8)
+    lvl_ref[...] = pack_bits(lvlf.astype(jnp.int32), bits)
+    sign_ref[...] = pack_bits(neg.astype(jnp.int32), 1)
 
     # hat <- hat + deq(payload), without re-reading the packed payload
     mag = lvlf * deq_ref[...][:, None, :]
@@ -131,7 +124,7 @@ def _fused_encode_kernel(enc_ref, deq_ref, tn_ref, hat_ref, xi_ref,
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret", "with_digest"))
 def fused_encode_pallas(theta_new, hat, xi, scales, bits: int,
-                        interpret: bool = True, with_digest: bool = False):
+                        interpret: bool, with_digest: bool = False):
     """theta_new/hat: [m, R, 128] (leaf dtype), xi: [m, R, 128] f32,
     scales: [m, 2] f32 — per-node (encode scale, dequant scale).
 
@@ -187,27 +180,19 @@ def _fused_mix_kernel(wscale_ref, lvl_ref, sign_ref, s_ref, s_new_ref,
                       *, bits: int, nshifts: int):
     """One row-block across all m nodes: decode every rolled payload tile and
     accumulate the weighted sum straight into the s update."""
-    pack = 8 // bits
-    maxlvl = (1 << bits) - 1
-    shifts = (jnp.arange(pack, dtype=jnp.uint32) * bits).reshape(1, 1, pack, 1)
-    sshift = jnp.arange(8, dtype=jnp.uint32).reshape(1, 1, 8, 1)
-
     s_blk = s_ref[...]
     wscale = wscale_ref[...]
     acc = jnp.zeros(s_blk.shape, jnp.float32)
     for k in range(nshifts):  # static unroll over the circulant decomposition
-        pk_l = lvl_ref[k].astype(jnp.uint32)
-        pk_s = sign_ref[k].astype(jnp.uint32)
-        m, prows, _ = pk_l.shape
-        lvl = ((pk_l[:, :, None, :] >> shifts) & maxlvl).reshape(m, prows * pack, LANES)
-        sign = ((pk_s[:, :, None, :] >> sshift) & 1).reshape(m, prows * pack, LANES)
+        lvl = unpack_bits(lvl_ref[k], bits)
+        sign = unpack_bits(sign_ref[k], 1)
         mag = lvl.astype(jnp.float32) * wscale[k][:, None, :]
         acc = acc + jnp.where(sign == 1, -mag, mag)
     s_new_ref[...] = (s_blk.astype(jnp.float32) + acc).astype(s_new_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
-def fused_mix_pallas(rolled_lvl, rolled_sign, s, wscale, bits: int, interpret: bool = True):
+def fused_mix_pallas(rolled_lvl, rolled_sign, s, wscale, bits: int, interpret: bool):
     """rolled_lvl: [K, m, R/pack, 128] u8, rolled_sign: [K, m, R/8, 128] u8,
     s: [m, R, 128] (leaf dtype), wscale: [K, m] f32 with
     wscale[k, i] = w_k * deq_scale[(i - shift_k) mod m].
@@ -219,10 +204,14 @@ def fused_mix_pallas(rolled_lvl, rolled_sign, s, wscale, bits: int, interpret: b
     pack = 8 // bits
     rows = prows * pack
     assert s.shape == (m, rows, LANES)
-    # live buffers: s, s_new, f32 accumulator, plus K u8 payload tiles of
-    # (1/pack + 1/8) bytes per element — K-dependent (mesh has K = m shifts)
-    payload_f32 = K * (1.0 / pack + 0.125) / 4.0
-    block = _pick_block(rows, 8 * pack, m, f32_operands=3.0 + payload_f32)
+    # live f32-sized buffers: s and s_new (double-buffered), the f32
+    # accumulator and the in-kernel temporaries, plus per unrolled shift its
+    # unpacked int32 levels and signs and (double-buffered) u8 payload tiles
+    # of (1/pack + 1/8) bytes per element — K-dependent (mesh has K = m
+    # shifts).  Sized from the v5e compiler's scoped-VMEM report at
+    # qwen3-1.7b widths (tests/test_tpu_compile.py).
+    payload_f32 = 2 * K * (1.0 / pack + 0.125) / 4.0
+    block = _pick_block(rows, 8 * pack, m, f32_operands=8.0 + 2.0 * K + payload_f32)
     grid = (rows // block,)
     ws = jnp.broadcast_to(wscale[..., None], (K, m, LANES)).astype(jnp.float32)
     return pl.pallas_call(
@@ -242,7 +231,7 @@ def fused_mix_pallas(rolled_lvl, rolled_sign, s, wscale, bits: int, interpret: b
 
 # ------------------------------------------------------------- leaf round
 def fused_round_leaf(leaf, hat, s, key, shifts: Sequence[tuple[int, float]],
-                     gamma, bits: int, interpret: bool = True, *,
+                     gamma, bits: int, interpret: bool, *,
                      roll_fn=None, node_keys=None, with_digest: bool = False):
     """One CHOCO round for a stacked leaf [m, ...] on the fused fast path.
 
@@ -272,9 +261,7 @@ def fused_round_leaf(leaf, hat, s, key, shifts: Sequence[tuple[int, float]],
     theta_new = leaf + jnp.asarray(gamma, dtype) * (s - hat).astype(dtype)
     flat_tn = theta_new.reshape(m, -1)
     flat_hat = hat.reshape(m, -1)
-    norms = jax.vmap(
-        lambda a, b: jnp.linalg.norm((a - b).astype(jnp.float32).reshape(-1))
-    )(flat_tn, flat_hat)
+    norms = ordered_norm((flat_tn - flat_hat).astype(jnp.float32))
 
     pack = 8 // bits
     unit = 8 * pack * LANES

@@ -9,8 +9,8 @@ the cache at f32/bf16 width.
 
 This kernel fixes both:
 
-* grid (B, KV): each cell handles one (batch, kv-head) pair's G query heads
-  at once, so k/v stream through VMEM exactly once — no repeat.
+* each (batch, kv-head) grid cell handles that pair's G query heads at
+  once, so k/v stream through VMEM exactly once — no repeat.
 * opt-in int8 quantized-KV mode (``k_scale``/``v_scale`` per (position,
   kv-head), built by ``ref.quantize_kv_ref`` at cache-store time): dequant
   is fused into the contractions — scores scale by ``k_scale`` *after* the
@@ -20,9 +20,16 @@ This kernel fixes both:
 
 A ``valid`` row mask handles both linear caches (slots beyond ``pos``) and
 ring-buffer windowed caches (wrapped slot ages); masked slots use the same
-finite -1e30 sentinel as the other attention kernels.  The length loop runs
-over ``block_l`` slabs inside the kernel (whole-L VMEM residency is fine at
-serving cache lengths; L up to ~64k f32 at hd=64 fits comfortably).
+finite -1e30 sentinel as the other attention kernels.
+
+TPU layout: the grid is (B, KV, L / block_l) and the cache streams through
+it one ``block_l`` slab at a time, so VMEM holds O(block_l) whatever the
+cache length; the online-softmax carry lives in VMEM scratch across the
+sequential innermost axis (the ``sliding_window.py`` pattern).  The cache is
+viewed as [B, L, KV * hd] (a free reshape) so one kv head's slab is a
+(block_l, hd) block on the lane axis, which the TPU's (8, 128) tiling
+accepts when ``hd % 128 == 0``; the live mask and the int8 scales are laid
+out with the cache length on the lanes for the same reason.
 """
 from __future__ import annotations
 
@@ -32,52 +39,50 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention import NEG_INF
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, *rest, scale, block_l,
-                   num_l, quantized):
+def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, *rest, scale, quantized):
     if quantized:
-        ks_ref, vs_ref, o_ref = rest
+        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
-        (o_ref,) = rest
-    g, hd = q_ref.shape[2], q_ref.shape[3]
+        o_ref, acc_ref, m_ref, l_ref = rest
+    j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
     q = q_ref[0, 0].astype(jnp.float32) * scale  # [G, hd]
+    k = k_ref[0].astype(jnp.float32)  # [block_l, hd]
+    v = v_ref[0].astype(jnp.float32)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [G, block_l]
+    if quantized:
+        s = s * ks_ref[0, 0]
+    s = jnp.where(valid_ref[0] != 0, s, NEG_INF)
 
-    def body(j, carry):
-        acc, m_prev, l_prev = carry
-        sl = pl.dslice(j * block_l, block_l)
-        k = pl.load(k_ref, (pl.dslice(0, 1), sl, pl.dslice(0, 1),
-                            pl.dslice(0, hd)))[0, :, 0].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(0, 1), sl, pl.dslice(0, 1),
-                            pl.dslice(0, hd)))[0, :, 0].astype(jnp.float32)
-        s = q @ k.T  # [G, block_l]
-        if quantized:
-            ks = pl.load(ks_ref, (pl.dslice(0, 1), sl, pl.dslice(0, 1)))[
-                0, :, 0
-            ]
-            s = s * ks[None, :]
-        live = pl.load(valid_ref, (pl.dslice(0, 1), sl))[0] != 0
-        s = jnp.where(live[None, :], s, NEG_INF)
+    m_prev = m_ref[:, 0]
+    l_prev = l_ref[:, 0]
+    m_cur = jnp.maximum(m_prev, s.max(-1))
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.exp(s - m_cur[:, None])
+    l_cur = l_prev * alpha + p.sum(-1)
+    if quantized:
+        p = p * vs_ref[0, 0]
+    acc_ref[...] = acc_ref[...] * alpha[:, None] + p @ v
+    m_ref[...] = jnp.broadcast_to(m_cur[:, None], m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_cur[:, None], l_ref.shape)
 
-        m_cur = jnp.maximum(m_prev, s.max(-1))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_cur = l_prev * alpha + p.sum(-1)
-        if quantized:
-            vs = pl.load(vs_ref, (pl.dslice(0, 1), sl, pl.dslice(0, 1)))[
-                0, :, 0
-            ]
-            p = p * vs[None, :]
-        acc = acc * alpha[:, None] + p @ v
-        return acc, m_cur, l_cur
-
-    acc = jnp.zeros((g, hd), jnp.float32)
-    m = jnp.full((g,), NEG_INF, jnp.float32)
-    l = jnp.zeros((g,), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, num_l, body, (acc, m, l))
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _flush():
+        l_fin = l_ref[:, 0]
+        o_ref[0, 0] = (
+            acc_ref[...] / jnp.maximum(l_fin, 1e-30)[:, None]
+        ).astype(o_ref.dtype)
 
 
 def decode_attention_pallas(
@@ -102,32 +107,39 @@ def decode_attention_pallas(
     block_l = min(block_l, length)
     assert length % block_l == 0, (length, block_l)
 
-    kv_spec = pl.BlockSpec((1, length, 1, hd), lambda bi, h: (bi, 0, h, 0))
+    kv_spec = pl.BlockSpec((1, block_l, hd), lambda bi, h, j: (bi, j, h))
     in_specs = [
-        pl.BlockSpec((1, 1, g, hd), lambda bi, h: (bi, h, 0, 0)),
+        pl.BlockSpec((1, 1, g, hd), lambda bi, h, j: (bi, h, 0, 0)),
         kv_spec,
         kv_spec,
-        pl.BlockSpec((1, length), lambda bi, h: (bi, 0)),
+        pl.BlockSpec((1, 1, block_l), lambda bi, h, j: (bi, 0, j)),
     ]
-    operands = [q, k, v, valid.astype(jnp.int32)]
+    operands = [
+        q,
+        k.reshape(b, length, kv * hd),
+        v.reshape(b, length, kv * hd),
+        valid.astype(jnp.int32).reshape(b, 1, length),
+    ]
     if quantized:
-        sc_spec = pl.BlockSpec((1, length, 1), lambda bi, h: (bi, 0, h))
+        sc_spec = pl.BlockSpec((1, 1, 1, block_l), lambda bi, h, j: (bi, h, 0, j))
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+        operands += [
+            x.astype(jnp.float32).transpose(0, 2, 1)[:, :, None, :]
+            for x in (k_scale, v_scale)
+        ]
 
-    kernel = functools.partial(
-        _decode_kernel,
-        scale=scale,
-        block_l=block_l,
-        num_l=length // block_l,
-        quantized=quantized,
-    )
+    kernel = functools.partial(_decode_kernel, scale=scale, quantized=quantized)
     return pl.pallas_call(
         kernel,
-        grid=(b, kv),
+        grid=(b, kv, length // block_l),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda bi, h: (bi, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, g, hd), lambda bi, h, j: (bi, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((g, hd), jnp.float32),
+            pltpu.VMEM((g, 128), jnp.float32),
+            pltpu.VMEM((g, 128), jnp.float32),
+        ],
         interpret=interpret,
     )(*operands)
 

@@ -57,12 +57,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, window,
 
     def body(kj, carry):
         acc, m_prev, l_prev = carry
-        # index the leading (size-1) dim with a dslice, not a raw Python int:
-        # the interpreter's load-discharge rule requires Slice/array indices
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(kj * block_k, block_k), slice(None)))[0]
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(kj * block_k, block_k), slice(None)))[0]
-        k = k.astype(jnp.float32)
-        v = v.astype(jnp.float32)
+        kv_rows = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
+        k = k_ref[0, kv_rows, :].astype(jnp.float32)
+        v = v_ref[0, kv_rows, :].astype(jnp.float32)
         s = q @ k.T  # [block_q, block_k] — MXU matmul
         k_pos = kj * block_k + jax.lax.iota(jnp.int32, block_k)
         mask = jnp.ones((block_q, block_k), jnp.bool_)

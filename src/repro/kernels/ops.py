@@ -18,7 +18,7 @@ import numpy as np
 from repro.core.compression import Compressor
 from repro.kernels import quantize as qk
 from repro.kernels import topk as tk
-from repro.kernels.ref import LANES, tau_for
+from repro.kernels.ref import LANES, ordered_norm, tau_for
 
 
 def _interpret_default() -> bool:
@@ -39,7 +39,7 @@ def quantize(x: jax.Array, key: jax.Array, bits: int = 4, interpret: bool | None
     flat = x.reshape(-1).astype(jnp.float32)
     pack = 8 // bits
     grid = _pad_to_rows(flat, 8 * pack)
-    norm = jnp.linalg.norm(flat)
+    norm = ordered_norm(flat)
     xi = jax.random.uniform(key, grid.shape)
     lvl, sign = qk.quantize_pallas(grid, xi, norm, bits, interpret=interpret)
     return {"levels": lvl, "signs": sign, "norm": norm}
@@ -294,8 +294,9 @@ def decode_attention_kernel(
     Grouped-query heads are handled inside the kernel (no materialized
     ``_repeat_kv``); with ``k_scale``/``v_scale`` the cache is int8 and
     dequant fuses into the contractions.  ``impl`` auto-resolves to the
-    Pallas kernel on TPU and the fused-XLA twin elsewhere (interpret-mode
-    Pallas is a correctness oracle, not a serving fast path).
+    Pallas kernel on TPU when ``hd`` is a multiple of 128 and to the
+    fused-XLA twin otherwise (interpret-mode Pallas is a correctness
+    oracle, not a serving fast path).
     """
     from repro.kernels.decode import (
         decode_attention_fused_xla,
@@ -310,12 +311,16 @@ def decode_attention_kernel(
     # j*G+g belongs to kv head j — so [B,1,H,hd] reshapes straight to groups
     qg = q.reshape(B, KV, G, hd)
     if impl is None:
-        impl = "xla_fused" if _interpret_default() else "pallas"
+        # the kernel's per-head lane blocks need hd % 128 == 0 on the chip
+        on_chip = not _interpret_default() and hd % LANES == 0
+        impl = "pallas" if on_chip else "xla_fused"
     if impl == "pallas":
         if interpret is None:
             interpret = _interpret_default()
         L = k.shape[1]
-        block_l = next(b for b in (512, 256, 128, 64, 32, 16, 8, 1) if L % b == 0)
+        # a lane-dense slab, or the whole cache when L has no such divisor:
+        # both are legal TPU blocks
+        block_l = next((b for b in (512, 256, 128) if L % b == 0), L)
         out = decode_attention_pallas(
             qg, k, v, valid, k_scale=k_scale, v_scale=v_scale,
             block_l=block_l, interpret=interpret,

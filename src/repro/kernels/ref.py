@@ -25,11 +25,58 @@ BISECT_ITERS = 20
 
 
 # ----------------------------------------------------------------- quantize
-def _rows_for(d: int, pack: int) -> int:
-    """Pad flat length d up to a multiple of pack*8*LANES and return rows."""
-    unit = pack * 8 * LANES  # pack rows x sign rows x lanes alignment
-    padded = ((d + unit - 1) // unit) * unit
-    return padded // LANES
+def pack_bits(vals: jax.Array, width: int) -> jax.Array:
+    """Fold ``8 // width`` consecutive rows of ``width``-bit int32 values
+    [..., rows, 128] into one uint8 row -> [..., rows * width / 8, 128].
+
+    The lanes stay int32 until the final narrowing cast: the TPU compiler
+    has no float<->uint32 conversion, so the kernels and this oracle share
+    one int32 path (values are < 2^8, so int32 and uint32 agree bit for bit).
+    """
+    per_byte = 8 // width
+    *lead, rows, lanes = vals.shape
+    v = vals.reshape(*lead, rows // per_byte, per_byte, lanes)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 2) * width
+    return (v << shifts).sum(axis=-2).astype(jnp.uint8)
+
+
+def unpack_bits(packed: jax.Array, width: int) -> jax.Array:
+    """Inverse of :func:`pack_bits`: uint8 [..., prows, 128] -> int32
+    [..., prows * 8 / width, 128] in {0, ..., 2^width - 1}."""
+    per_byte = 8 // width
+    *lead, prows, lanes = packed.shape
+    p = jnp.broadcast_to(
+        packed.astype(jnp.int32)[..., None, :], (*lead, prows, per_byte, lanes)
+    )
+    shifts = jax.lax.broadcasted_iota(jnp.int32, p.shape, p.ndim - 2) * width
+    return ((p >> shifts) & ((1 << width) - 1)).reshape(*lead, prows * per_byte, lanes)
+
+
+def ordered_norm(x: jax.Array) -> jax.Array:
+    """L2 norm over the last axis of f32 ``x`` [..., d] with a fixed
+    summation order: pad to [..., rows, 128] lanes, then halve rows and then
+    lanes with elementwise adds.  XLA never reassociates those adds, so the
+    norm (and with it every quantization scale) is the same bits in any
+    program the round is compiled into, whatever the batch shape around it
+    or the backend.  ``jnp.linalg.norm`` leaves the order to the compiler,
+    which picks it per program."""
+    d = x.shape[-1]
+    pad = (-d) % LANES
+    sq = x.astype(jnp.float32) ** 2
+    if pad:
+        sq = jnp.pad(sq, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+    sq = sq.reshape(*x.shape[:-1], -1, LANES)
+    rows = lambda a, lo, hi: jax.lax.slice_in_dim(a, lo, hi, axis=-2)
+    while sq.shape[-2] > 1:
+        if sq.shape[-2] % 2:  # adding a zero row is exact
+            sq = jnp.concatenate([sq, jnp.zeros_like(rows(sq, 0, 1))], axis=-2)
+        half = sq.shape[-2] // 2
+        sq = rows(sq, 0, half) + rows(sq, half, 2 * half)
+    sq = sq[..., 0, :]
+    while sq.shape[-1] > 1:
+        half = sq.shape[-1] // 2
+        sq = sq[..., :half] + sq[..., half:]
+    return jnp.sqrt(sq[..., 0])
 
 
 def quantize_ref(x: jax.Array, xi: jax.Array, norm: jax.Array, bits: int):
@@ -38,22 +85,12 @@ def quantize_ref(x: jax.Array, xi: jax.Array, norm: jax.Array, bits: int):
     Returns (packed_levels [rows/pack, 128] uint8, packed_signs [rows/8, 128] uint8).
     """
     assert x.ndim == 2 and x.shape[1] == LANES
-    pack = 8 // bits
-    rows = x.shape[0]
     maxlvl = (1 << bits) - 1
     scale = (1 << bits) / jnp.maximum(norm, 1e-30)
     q = jnp.floor(jnp.abs(x) * scale + xi)
-    lvl = jnp.clip(q, 0, maxlvl).astype(jnp.uint8)
-    sign = (x < 0).astype(jnp.uint8)
-
-    l = lvl.reshape(rows // pack, pack, LANES).astype(jnp.uint32)
-    shifts = (jnp.arange(pack, dtype=jnp.uint32) * bits).reshape(1, pack, 1)
-    packed_lvl = (l << shifts).sum(axis=1).astype(jnp.uint8)
-
-    s = sign.reshape(rows // 8, 8, LANES).astype(jnp.uint32)
-    sshift = jnp.arange(8, dtype=jnp.uint32).reshape(1, 8, 1)
-    packed_sign = (s << sshift).sum(axis=1).astype(jnp.uint8)
-    return packed_lvl, packed_sign
+    lvl = jnp.clip(q, 0, maxlvl).astype(jnp.int32)
+    sign = (x < 0).astype(jnp.int32)
+    return pack_bits(lvl, bits), pack_bits(sign, 1)
 
 
 def tau_for(d: int, bits: int) -> float:
@@ -69,17 +106,8 @@ def dequantize_ref(packed_lvl: jax.Array, packed_sign: jax.Array, scale: jax.Arr
     roundtrip a delta = 1/tau contraction (without it the unbiased decode has
     variance (tau-1)||x||^2, which explodes for small b / large d).
     """
-    pack = 8 // bits
-    rows = packed_lvl.shape[0] * pack
-    maxlvl = (1 << bits) - 1
-    l = packed_lvl.astype(jnp.uint32)[:, None, :]
-    shifts = (jnp.arange(pack, dtype=jnp.uint32) * bits).reshape(1, pack, 1)
-    lvl = ((l >> shifts) & maxlvl).reshape(rows, LANES).astype(jnp.float32)
-
-    s = packed_sign.astype(jnp.uint32)[:, None, :]
-    sshift = jnp.arange(8, dtype=jnp.uint32).reshape(1, 8, 1)
-    sign = ((s >> sshift) & 1).reshape(rows, LANES)
-
+    lvl = unpack_bits(packed_lvl, bits).astype(jnp.float32)
+    sign = unpack_bits(packed_sign, 1)
     mag = lvl * scale
     return jnp.where(sign == 1, -mag, mag)
 
@@ -98,14 +126,8 @@ def fused_encode_ref(theta_new, hat, xi, scales, bits: int):
     lvlf = jnp.clip(q, 0, (1 << bits) - 1)
     neg = resid < 0
 
-    def pack_nodes(vals, per_byte, width):
-        m, rows, _ = vals.shape
-        v = vals.reshape(m, rows // per_byte, per_byte, LANES).astype(jnp.uint32)
-        sh = (jnp.arange(per_byte, dtype=jnp.uint32) * width).reshape(1, 1, per_byte, 1)
-        return (v << sh).sum(axis=2).astype(jnp.uint8)
-
-    packed_lvl = pack_nodes(lvlf.astype(jnp.uint32), 8 // bits, bits)
-    packed_sign = pack_nodes(neg.astype(jnp.uint32), 8, 1)
+    packed_lvl = pack_bits(lvlf.astype(jnp.int32), bits)
+    packed_sign = pack_bits(neg.astype(jnp.int32), 1)
     mag = lvlf * scales[:, 1, None, None]
     hat_new = (hat.astype(jnp.float32) + jnp.where(neg, -mag, mag)).astype(hat.dtype)
     return packed_lvl, packed_sign, hat_new

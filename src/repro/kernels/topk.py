@@ -41,7 +41,7 @@ def _block_topk_kernel(x_ref, out_ref, *, k: int, iters: int):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "iters", "interpret"))
-def block_topk_pallas(x: jax.Array, k: int, iters: int = BISECT_ITERS, interpret: bool = True):
+def block_topk_pallas(x: jax.Array, k: int, iters: int = BISECT_ITERS, *, interpret: bool):
     """x: [num_blocks, block] f32; returns same shape, masked to ~top-k per row."""
     assert x.ndim == 2 and x.shape[1] % 128 == 0
     nb, block = x.shape

@@ -156,8 +156,6 @@ def run_pair(arch: str, shape_name: str, multi_pod: bool, *, verbose: bool = Tru
         except Exception as e:
             print(f"(memory_analysis unavailable: {e})")
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):
-            cost = cost[0]
         print({k: v for k, v in sorted(cost.items()) if k in ("flops", "bytes accessed")})
         print(
             f"{arch} x {shape_name} @ {meta['mesh']}: "

@@ -11,6 +11,7 @@ Functions, not module constants, so importing never initializes devices.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = [
     "make_production_mesh",
@@ -23,6 +24,15 @@ __all__ = [
 NODE_AXIS = "nodes"  # logical name used in PartitionSpecs for the AD-GDA node dim
 
 
+def _auto_mesh(shape, axes, devices=None):
+    """A mesh whose axes the compiler partitions automatically (GSPMD): the
+    code places arrays with NamedShardings and leaves propagation to XLA,
+    so no axis carries its sharding in the array types."""
+    return jax.make_mesh(
+        shape, axes, devices=devices, axis_types=(AxisType.Auto,) * len(axes)
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
@@ -33,7 +43,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"production mesh needs {need} devices, found {len(devices)} — "
             "run via repro.launch.dryrun (which forces 512 host devices) or on real hardware"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return _auto_mesh(shape, axes, devices[:need])
 
 
 def node_axes(mesh) -> tuple[str, ...]:
@@ -48,7 +58,7 @@ def num_nodes(mesh) -> int:
 
 def make_cpu_mesh(data: int = 1, model: int = 1):
     """Tiny mesh for CPU smoke/integration tests on the real local devices."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_node_mesh(num_nodes: int):
@@ -63,4 +73,4 @@ def make_node_mesh(num_nodes: int):
     """
     avail = len(jax.devices())
     data = max(k for k in range(1, min(avail, num_nodes) + 1) if num_nodes % k == 0)
-    return jax.make_mesh((data, 1), ("data", "model"), devices=jax.devices()[:data])
+    return _auto_mesh((data, 1), ("data", "model"), jax.devices()[:data])

@@ -35,6 +35,7 @@ import numpy as np
 from repro.checkpoint import latest_step, restore, step_path
 from repro.configs import get_config
 from repro.launch import steps as st
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import transformer as T
 
 
@@ -186,6 +187,7 @@ def main() -> None:
                             "batch-1 prefill, full-pool decode) -- tick "
                             "metrics are bit-identical, only wall differs")
     args = ap.parse_args()
+    configure_compile_cache()
 
     if args.follow and not (args.fleet and args.restore):
         ap.error("--follow needs --fleet N and --restore <step-tagged prefix>")

@@ -13,11 +13,13 @@ import jax
 
 from repro.core.adgda import ADGDAConfig, adgda_trainer
 from repro.core.trainer import DecentralizedTrainer
+from repro.launch.sharding import node_shardings
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 
 __all__ = [
     "make_trainer",
+    "init_trainer_state",
     "make_prefill_step",
     "make_decode_step",
     "abstract_params",
@@ -97,6 +99,28 @@ def make_trainer(
         spmd_axis_name=spmd_axis_name,
     )
     return adgda_trainer(adgda_cfg, loss_fn, mesh=mesh, node_axes=node_axes)
+
+
+def init_trainer_state(trainer: DecentralizedTrainer, cfg: ModelConfig,
+                       key: jax.Array, rng: jax.Array, mesh=None):
+    """Random model parameters and the trainer state built on them, in one
+    jitted program whose outputs land where the step reads them.
+
+    With ``mesh`` every stacked ``[num_nodes, ...]`` leaf is placed node-
+    sharded over its ``data`` axis (``sharding.node_shardings``), so no
+    device ever holds more than its own nodes; without one the state lands on
+    the default device. No host copy and no unstacked parameter tree outlive
+    the program."""
+
+    def init(k, r):
+        return trainer.init(T.init_model(k, cfg), r)
+
+    if mesh is None:
+        return jax.jit(init)(key, rng)
+    shardings = node_shardings(
+        jax.eval_shape(init, key, rng), mesh, trainer.num_nodes
+    )
+    return jax.jit(init, out_shardings=shardings)(key, rng)
 
 
 def make_prefill_step(cfg: ModelConfig, cache_len: int):

@@ -35,8 +35,9 @@ from repro.checkpoint import restore_latest, save
 from repro.configs import get_config
 from repro.data import node_token_stream
 from repro.launch import steps as st
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_node_mesh
-from repro.models import transformer as T
+from repro.launch.sharding import node_shardings
 
 
 def main() -> None:
@@ -111,6 +112,7 @@ def main() -> None:
                     help="write final losses/consensus_err to this JSON file")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -159,8 +161,9 @@ def main() -> None:
     )
 
     key = jax.random.PRNGKey(args.seed)
-    params = T.init_model(key, cfg)
-    n_params = sum(int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(params))
+    n_params = sum(
+        int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(st.abstract_params(cfg))
+    )
     wire = args.topology_schedule or args.topology
     if args.dropout:
         wire += f"+drop{args.dropout:g}"
@@ -180,16 +183,22 @@ def main() -> None:
         # recompute, and the continuation is bit-identical to a run that
         # never stopped.  restore_latest skips any unreadable file and falls
         # back to the last complete checkpoint instead of crashing.
-        template = jax.eval_shape(trainer.init, params, init_rng)
+        template = st.abstract_trainer_state(trainer, cfg)
         state, found = restore_latest(args.checkpoint, template)
         if found is None:
             print(f"--resume: no loadable checkpoint under {args.checkpoint!r}; starting fresh")
-            state = trainer.init(params, init_rng)
+            state = st.init_trainer_state(trainer, cfg, key, init_rng, mesh)
         else:
             start_step = found
             print(f"resumed full trainer state from step {found}")
+            if mesh is not None:
+                state = jax.device_put(
+                    state, node_shardings(state, mesh, args.nodes)
+                )
     else:
-        state = trainer.init(params, init_rng)
+        # built straight into its node shards: no device ever holds the
+        # whole stacked state
+        state = st.init_trainer_state(trainer, cfg, key, init_rng, mesh)
 
     # one round consumes local_steps x the per-node batch (K local updates)
     round_batch = args.batch_per_node * args.local_steps
@@ -198,7 +207,7 @@ def main() -> None:
         next(stream)
 
     def make_batch(tokens):
-        batch = {"tokens": jnp.asarray(tokens)}
+        batch = {"tokens": tokens}
         if cfg.is_encdec:
             batch["frames"] = jnp.zeros(
                 (args.nodes, round_batch, cfg.encoder_context, cfg.d_model), jnp.float32
@@ -207,7 +216,9 @@ def main() -> None:
             batch["patches"] = jnp.zeros(
                 (args.nodes, round_batch, cfg.num_patches, cfg.d_model), jnp.float32
             )
-        return batch
+        if mesh is not None:
+            return jax.device_put(batch, node_shardings(batch, mesh, args.nodes))
+        return jax.tree.map(jnp.asarray, batch)
 
     aux = None
     t0 = time.time()

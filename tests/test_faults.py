@@ -145,9 +145,9 @@ def test_fused_encode_digest_parity(dtype, d):
     hat = (jax.random.normal(ks[1], (m, d)) * 0.1).astype(dtype)
     s = jnp.zeros_like(leaf)
     shifts = ((1, 0.3), (3, 0.2))
-    plain = fused_round_leaf(leaf, hat, s, ks[2], shifts, 0.5, 4)
+    plain = fused_round_leaf(leaf, hat, s, ks[2], shifts, 0.5, 4, interpret=True)
     tn, hn, sn, dig = fused_round_leaf(
-        leaf, hat, s, ks[2], shifts, 0.5, 4, with_digest=True
+        leaf, hat, s, ks[2], shifts, 0.5, 4, interpret=True, with_digest=True
     )
     for a, b in zip(plain, (tn, hn, sn)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

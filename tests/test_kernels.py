@@ -154,3 +154,33 @@ def test_kernel_vs_core_block_topk_equivalence():
     e_exact = float(jnp.sum(exact**2))
     e_kern = float(jnp.sum(kern**2))
     assert abs(e_exact - e_kern) / e_exact < 0.02
+
+
+# ------------------------------------------------------- payload primitives
+@pytest.mark.parametrize("width", [1, 2, 4, 8])
+def test_pack_bits_roundtrip_and_layout(width):
+    """int32 packing folds 8 // width consecutive rows into one uint8 row,
+    low bits first (the wire format the uint32 formulation produced)."""
+    per_byte = 8 // width
+    vals = jax.random.randint(KEY, (3, 8 * per_byte, 128), 0, 1 << width, jnp.int32)
+    packed = ref.pack_bits(vals, width)
+    assert packed.dtype == jnp.uint8 and packed.shape == (3, 8, 128)
+    v = np.asarray(vals).reshape(3, 8, per_byte, 128).astype(np.uint32)
+    want = sum(v[:, :, j] << (j * width) for j in range(per_byte)).astype(np.uint8)
+    np.testing.assert_array_equal(np.asarray(packed), want)
+    np.testing.assert_array_equal(np.asarray(ref.unpack_bits(packed, width)), np.asarray(vals))
+
+
+@pytest.mark.parametrize("d", [7, 300, 128 * 37 + 5])
+def test_ordered_norm_is_program_independent(d):
+    """The quantization norm has one value per row whatever program computes
+    it: batched or vmapped, inside a bigger batch or a slice of it."""
+    x = jax.random.normal(KEY, (8, d), jnp.float32)
+    full = np.asarray(jax.jit(ref.ordered_norm)(x))
+    sliced = np.asarray(jax.jit(ref.ordered_norm)(x[:2]))
+    vmapped = np.asarray(jax.jit(jax.vmap(ref.ordered_norm))(x))
+    eager = np.asarray(ref.ordered_norm(x[5]))
+    np.testing.assert_array_equal(full[:2], sliced)
+    np.testing.assert_array_equal(full, vmapped)
+    assert full[5] == eager
+    np.testing.assert_allclose(full, np.linalg.norm(np.asarray(x, np.float64), axis=1), rtol=1e-6)

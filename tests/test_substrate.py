@@ -58,6 +58,24 @@ def test_token_stream_node_skew():
     assert len(set(tops)) > 1
 
 
+@pytest.mark.parametrize("seed", [0, 1, 7])
+def test_token_stream_matches_repeat_gather(seed):
+    """The direct per-node index emits the stream the [m, b, S, V] repeat +
+    take_along_axis expression did, batch for batch."""
+    m, b, S, V = 3, 2, 16, 50
+    gen = node_token_stream(m, b, S, V, seed=seed)
+    rng = np.random.default_rng(seed)
+    probs = np.arange(1, V + 1, dtype=np.float64) ** -1.2
+    probs /= probs.sum()
+    perms = np.stack([rng.permutation(V) for _ in range(m)])
+    for _ in range(3):
+        base = rng.choice(V, size=(m, b, S), p=probs)
+        old = np.take_along_axis(
+            perms[:, None, None, :].repeat(b, 1).repeat(S, 2), base[..., None], axis=-1
+        )[..., 0].astype(np.int32)
+        np.testing.assert_array_equal(next(gen), old)
+
+
 # ------------------------------------------------------------------- optim
 def test_sgd_quadratic_converges():
     opt = sgd(0.1, momentum=0.9)

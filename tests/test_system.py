@@ -17,6 +17,8 @@ from repro.data import (
     rotated_minority_classification,
 )
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 KEY = jax.random.PRNGKey(0)
 
 
@@ -160,7 +162,7 @@ def test_train_driver_end_to_end(tmp_path):
          "--reduced", "--steps", "3", "--nodes", "2", "--batch-per-node", "1",
          "--seq", "32", "--log-every", "1",
          "--checkpoint", str(tmp_path / "ckpt")],
-        capture_output=True, text=True, cwd="/root/repo", env=env, timeout=900,
+        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
     )
     assert out.returncode == 0, out.stderr[-2000:]
     assert "worst=" in out.stdout
@@ -172,10 +174,52 @@ def test_serve_driver_end_to_end():
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch", "recurrentgemma-2b",
          "--reduced", "--batch", "2", "--prompt-len", "16", "--gen", "6"],
-        capture_output=True, text=True, cwd="/root/repo", env=env, timeout=900,
+        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
     )
     assert out.returncode == 0, out.stderr[-2000:]
     assert "ms/token" in out.stdout
+
+
+@pytest.mark.parametrize("alone", [False, True], ids=["in-repo", "alone"])
+def test_chip_smoke_fails_without_tpu(tmp_path, alone):
+    """No accelerator, no result: the smoke never falls back to the CPU."""
+    import shutil
+
+    script = os.path.join(REPO, "chip_smoke.py")
+    if alone:
+        script = shutil.copy(script, tmp_path / "chip_smoke.py")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    out = subprocess.run([sys.executable, str(script)], capture_output=True,
+                         text=True, cwd=os.path.dirname(script), env=env, timeout=120)
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+    # alone, the import of the program fails first
+    assert ("ModuleNotFoundError" if alone else "no TPU") in out.stderr
+
+
+@pytest.mark.parametrize("from_env", [False, True], ids=["repo-default", "env"])
+def test_compile_cache_location(tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; without it the cache sits at
+    one fixed, git-ignored path inside the checkout."""
+    env = dict(os.environ, PYTHONPATH="src")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cc")
+    code = (
+        "import jax; from repro.launch.compile_cache import configure_compile_cache as c; "
+        "print(c()); print(jax.config.jax_compilation_cache_dir)"
+    )
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, cwd=REPO, env=env, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    chosen, configured = out.stdout.split()[-2:]
+    if from_env:
+        assert chosen == configured == str(tmp_path / "cc")
+    else:
+        assert chosen == configured == os.path.join(REPO, ".jax_cache")
+        with open(os.path.join(REPO, ".gitignore")) as fh:
+            assert ".jax_cache/" in fh.read().split()
 
 
 def test_checkpoint_roundtrip_model(tmp_path):
