@@ -1,0 +1,10 @@
+"""Benchmark tests: run explicitly (``python -m pytest bench/tests``); the
+repository's own suite collects only ``tests/``. They run on the CPU at tiny
+sizes; nothing here needs a chip."""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (str(BENCH.parent / "src"), str(BENCH)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
