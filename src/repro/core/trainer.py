@@ -1311,66 +1311,73 @@ class DecentralizedTrainer:
             else None
         )
 
-        ctx = self.dual.begin(state.lam, dual_key)
+        # Each phase of Algorithm 1 runs under a named scope: the compiled
+        # ops carry it in their op_name, so a profiler trace of the round
+        # splits its device time by phase.  Scopes are metadata only.
+        with jax.named_scope("adgda.dual"):
+            ctx = self.dual.begin(state.lam, dual_key)
 
         # --- local oracle + optimizer (dual-weighted gradients) -------------
         theta = self._stacked(state.theta) if self.federated else state.theta
-        weights_fn = lambda losses: self.dual.grad_weights(state.lam, losses)
-        theta_half, opt_new, losses = self.local.step(
-            self.loss_fn, theta, state.opt, batch, node_keys, weights_fn
-        )
-        if mask is not None:
-            # dropped nodes skip their local update: model and per-node
-            # optimizer moments revert, so a rejoining node resumes from
-            # exactly where it left off
-            theta_half = _select_nodes(mask, theta_half, theta, m)
-            opt_new = _select_nodes(mask, opt_new, state.opt, m)
+        with jax.named_scope("adgda.local"):
+            weights_fn = lambda losses: self.dual.grad_weights(state.lam, losses)
+            theta_half, opt_new, losses = self.local.step(
+                self.loss_fn, theta, state.opt, batch, node_keys, weights_fn
+            )
+            if mask is not None:
+                # dropped nodes skip their local update: model and per-node
+                # optimizer moments revert, so a rejoining node resumes from
+                # exactly where it left off
+                theta_half = _select_nodes(mask, theta_half, theta, m)
+                opt_new = _select_nodes(mask, opt_new, state.opt, m)
 
         # --- dual update ----------------------------------------------------
-        lam_new = self.dual.update(
-            state.lam, losses, ctx, mixing=mixing, mask=mask, step=state.step,
-            fault_key=fault_key,
-        )
+        with jax.named_scope("adgda.dual"):
+            lam_new = self.dual.update(
+                state.lam, losses, ctx, mixing=mixing, mask=mask, step=state.step,
+                fault_key=fault_key,
+            )
 
         # --- consensus ------------------------------------------------------
-        theta_new, cons_new = self.consensus.mix(
-            theta_half, state.consensus, gossip_key, ctx,
-            step=state.step, mask=mask, mixing=mixing, fault_key=fault_key,
-            theta_prev=theta,
-        )
-
-        # --- running average of the network mean (output theta_o) -----------
-        if self.track_average:
-            tt = state.step.astype(jnp.float32)
-            mean = (lambda th: th.astype(jnp.float32)) if self.federated else (
-                lambda th: th.astype(jnp.float32).mean(0)
+        with jax.named_scope("adgda.gossip"):
+            theta_new, cons_new = self.consensus.mix(
+                theta_half, state.consensus, gossip_key, ctx,
+                step=state.step, mask=mask, mixing=mixing, fault_key=fault_key,
+                theta_prev=theta,
             )
-            theta_avg = jax.tree.map(
-                lambda avg, th: (avg * tt + mean(th)) / (tt + 1.0),
-                state.theta_avg,
-                theta_new,
-            )
-        else:
-            theta_avg = ()
 
-        aux = {
-            "losses": losses,
-            "worst_loss": losses.max(),
-            "mean_loss": losses.mean(),
-            "lambda_mean": lam_new.mean(0) if lam_new.ndim == 2 else lam_new,
-            "eta_theta": self.local.lr(state.opt),
-        }
-        if not self.federated:
-            aux["consensus_err"] = _consensus_error(theta_new)
-        if mask is not None:
-            aux["participation"] = mask
-        # jitted realized-bits meter: this round's measured wire traffic
-        # (model payload + the dual's constant), no host-side masks needed;
-        # faulted wires read the exchange's own delivered-bits meter out of
-        # the post-mix consensus state instead of a degree formula
-        aux["bits_realized"] = self.consensus.bits_realized(
-            state.theta, state.step, mask, consensus_state=cons_new
-        ) + jnp.float32(self.dual.bits_per_round())
+        with jax.named_scope("adgda.telemetry"):
+            # --- running average of the network mean (output theta_o) -------
+            if self.track_average:
+                tt = state.step.astype(jnp.float32)
+                mean = (lambda th: th.astype(jnp.float32)) if self.federated else (
+                    lambda th: th.astype(jnp.float32).mean(0)
+                )
+                theta_avg = jax.tree.map(
+                    lambda avg, th: (avg * tt + mean(th)) / (tt + 1.0),
+                    state.theta_avg,
+                    theta_new,
+                )
+            else:
+                theta_avg = ()
+
+            aux = {
+                "losses": losses,
+                "worst_loss": losses.max(),
+                "mean_loss": losses.mean(),
+                "lambda_mean": lam_new.mean(0) if lam_new.ndim == 2 else lam_new,
+            }
+            if not self.federated:
+                aux["consensus_err"] = _consensus_error(theta_new)
+            if mask is not None:
+                aux["participation"] = mask
+            # jitted realized-bits meter: this round's measured wire traffic
+            # (model payload + the dual's constant), no host-side masks needed;
+            # faulted wires read the exchange's own delivered-bits meter out of
+            # the post-mix consensus state instead of a degree formula
+            aux["bits_realized"] = self.consensus.bits_realized(
+                state.theta, state.step, mask, consensus_state=cons_new
+            ) + jnp.float32(self.dual.bits_per_round())
 
         new_state = TrainerState(
             step=state.step + 1,

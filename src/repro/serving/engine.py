@@ -39,7 +39,7 @@ instead of recompiling per engine — compile time dominated the pre-fastpath
 suite.  The legacy path's per-engine ``_prefills`` dict is LRU-bounded too
 (``max_prefill_programs``) so many distinct exact-length prefills
 (recurrent/windowed archs) can no longer grow the jit cache without bound;
-``engine.stats()`` exposes sizes, hits and evictions.
+``engine.stats()`` exposes sizes and evictions.
 
 Admission is strictly FIFO: each tick runs an admit/finish fixpoint, so a
 request that completes *at prefill* (single-token budget, or EOS emitted as
@@ -50,6 +50,16 @@ timestamps (submit/admit/first-token/finish) consumed by the fleet metrics
 layer (`repro.serving.metrics`); ``prefill_traces`` / ``decode_traces``
 count program builds triggered by this engine so the bounded-trace-set
 claim stays testable (clear ``PROGRAMS`` first when pinning counts).
+
+Each tick records host spans on the JAX profiler's clock
+(``jax.profiler.TraceAnnotation``), so a trace ties every device op and idle
+gap to what the engine was doing: ``engine.step`` (the tick; stats ``tick``,
+``admitted``, ``decoded``, ``built``) and, on the fast path, inside it
+``engine.admit`` (the admit/finish fixpoint), ``engine.prefill`` (one per
+bucket group; ``bucket``, ``rows``, ``bpad``), ``engine.decode`` (input upload
+and dispatch; ``bpad``, ``occupancy``), ``engine.sample`` (key split, sampler,
+host read of the tokens) and ``engine.retire`` (per-slot bookkeeping). With
+the profiler off a span is a no-op; spans add no device sync.
 
 This is the production shape of the ``decode_32k`` dry-run: the engine is
 the host-side loop, the vmapped decode step is the device program.
@@ -65,6 +75,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
@@ -82,8 +93,10 @@ class Request:
     output: list[int] = dataclasses.field(default_factory=list)
     done: bool = False
     # lifecycle + timing, stamped by the engine/fleet (ticks are engine
-    # steps; walls are host seconds).  first token lands at admit (the
-    # prefill emits it), so TTFT = admit_tick - submit_tick = queue wait.
+    # steps; walls are host seconds).  The first token is emitted by the
+    # prefill at admit, so TTFT in ticks = admit_tick - submit_tick = queue
+    # wait; first_wall is stamped when that token reaches the host, so wall
+    # TTFT = queue wait + prefill.
     status: str = "queued"  # queued | active | done | rejected | shed
     submit_tick: int = -1
     admit_tick: int = -1
@@ -134,13 +147,11 @@ class ProgramCache:
         self.maxsize = maxsize
         self._programs: OrderedDict[tuple, Callable] = OrderedDict()
         self.builds = 0
-        self.hits = 0
         self.evictions = 0
 
     def get(self, key: tuple, build: Callable[[], Callable]):
         if key in self._programs:
             self._programs.move_to_end(key)
-            self.hits += 1
             return self._programs[key], False
         fn = build()
         self.builds += 1
@@ -155,7 +166,7 @@ class ProgramCache:
 
     def clear(self) -> None:
         self._programs.clear()
-        self.builds = self.hits = self.evictions = 0
+        self.builds = self.evictions = 0
 
 
 #: process-wide fast-path program cache (tests pinning trace counts should
@@ -450,6 +461,9 @@ class ServeEngine:
         return min(_round_up(plen, self.prompt_bucket), self.cache_len)
 
     def _post_admit(self, req: Request, slot: int, first: int, plen: int) -> None:
+        # the first token is on the host now (read from the prefill, or kept
+        # by the prefix cache): wall TTFT includes the prefill
+        req.first_wall = time.time()
         # NOTE: bucket-padded positions beyond plen hold garbage K/V; decode
         # masks by position (pos = plen), so they are never attended.
         self.pos[slot] = plen
@@ -461,7 +475,6 @@ class ServeEngine:
     def _admit(self, req: Request, slot: int) -> None:
         """Legacy admission: one batch-1 prefill forward per request."""
         req.admit_tick = self._steps
-        req.first_wall = time.time()
         req.status = "active"
         plen = len(req.prompt)
         bucket = self._bucket_for(req)
@@ -490,7 +503,6 @@ class ServeEngine:
         hits, misses = [], []
         for req, slot in pairs:
             req.admit_tick = self._steps
-            req.first_wall = time.time()
             req.status = "active"
             plen = len(req.prompt)
             bucket = self._bucket_for(req)
@@ -541,44 +553,45 @@ class ServeEngine:
             [group] if self._batched_prefill
             else [[item] for item in group]
         )
-        for chunk in chunks:
-            toks = np.zeros((bpad, bucket), np.int32)
-            last = np.zeros(bpad, np.int32)
-            for r, (req, _, _, _, plen) in enumerate(chunk):
-                toks[r, :plen] = req.prompt
-                last[r] = plen - 1
-            batch = {"tokens": jnp.asarray(toks), **{
-                k: (jnp.broadcast_to(jnp.asarray(v)[None],
-                                     (bpad,) + tuple(np.shape(v)))
-                    if hasattr(v, "ndim") else v)
-                for k, v in self.extra_inputs.items()
-            }}
-            prefill = self._program(
-                "prefill", bucket, bpad, counter="prefill_traces"
-            )
-            logits, cache_b = prefill(self.params, batch)
-            # first generated token per row: argmax at its last REAL position
-            firsts = np.asarray(jnp.argmax(
-                logits[jnp.arange(bpad), jnp.asarray(last)], axis=-1
-            ))
-            # one scatter splices every row into its slot; pad rows target
-            # max_slots and are dropped out-of-bounds
-            sidx = np.full(bpad, self.max_slots, np.int32)
-            for r, (_, slot, _, _, _) in enumerate(chunk):
-                sidx[r] = slot
-            scatter = self._program("scatter", bpad)
-            self.cache = scatter(self.cache, cache_b, jnp.asarray(sidx))
-            takerow = None
-            for r, (req, slot, _, key, plen) in enumerate(chunk):
-                if key is not None and key not in self._prefix:
-                    if takerow is None:
-                        takerow = self._program("takerow", bpad)
-                    self._prefix[key] = (takerow(cache_b, np.int32(r)),
-                                         int(firsts[r]))
-                    if len(self._prefix) > self._prefix_max:
-                        self._prefix.popitem(last=False)
-                        self.prefix_evictions += 1
-                self._post_admit(req, slot, int(firsts[r]), plen)
+        with TraceAnnotation("engine.prefill", bucket=bucket, rows=len(group), bpad=bpad):
+            for chunk in chunks:
+                toks = np.zeros((bpad, bucket), np.int32)
+                last = np.zeros(bpad, np.int32)
+                for r, (req, _, _, _, plen) in enumerate(chunk):
+                    toks[r, :plen] = req.prompt
+                    last[r] = plen - 1
+                batch = {"tokens": jnp.asarray(toks), **{
+                    k: (jnp.broadcast_to(jnp.asarray(v)[None],
+                                         (bpad,) + tuple(np.shape(v)))
+                        if hasattr(v, "ndim") else v)
+                    for k, v in self.extra_inputs.items()
+                }}
+                prefill = self._program(
+                    "prefill", bucket, bpad, counter="prefill_traces"
+                )
+                logits, cache_b = prefill(self.params, batch)
+                # first generated token per row: argmax at its last REAL position
+                firsts = np.asarray(jnp.argmax(
+                    logits[jnp.arange(bpad), jnp.asarray(last)], axis=-1
+                ))
+                # one scatter splices every row into its slot; pad rows target
+                # max_slots and are dropped out-of-bounds
+                sidx = np.full(bpad, self.max_slots, np.int32)
+                for r, (_, slot, _, _, _) in enumerate(chunk):
+                    sidx[r] = slot
+                scatter = self._program("scatter", bpad)
+                self.cache = scatter(self.cache, cache_b, jnp.asarray(sidx))
+                takerow = None
+                for r, (req, slot, _, key, plen) in enumerate(chunk):
+                    if key is not None and key not in self._prefix:
+                        if takerow is None:
+                            takerow = self._program("takerow", bpad)
+                        self._prefix[key] = (takerow(cache_b, np.int32(r)),
+                                             int(firsts[r]))
+                        if len(self._prefix) > self._prefix_max:
+                            self._prefix.popitem(last=False)
+                            self.prefix_evictions += 1
+                    self._post_admit(req, slot, int(firsts[r]), plen)
 
     # -------------------------------------------------------------- API
     def submit(self, req: Request) -> int:
@@ -616,37 +629,40 @@ class ServeEngine:
         order = sorted(self.active)
         n = len(order)
         bpad = _pow2(n) if (self._active_decode and n < self.max_slots) else self.max_slots
-        if bpad >= self.max_slots:
-            decode = self._program("decode", counter="decode_traces")
-            logits, self.cache = decode(
-                self.params, jnp.asarray(self.last_tok), self.cache,
-                jnp.asarray(self.pos),
-            )
-            rows = {slot: slot for slot in order}
-        else:
-            gidx = np.empty(bpad, np.int32)
-            gidx[:n] = order
-            gidx[n:] = order[0]
-            sidx = np.full(bpad, self.max_slots, np.int32)
-            sidx[:n] = order
-            decode = self._program("decodeg", bpad, counter="decode_traces")
-            logits, self.cache = decode(
-                self.params, jnp.asarray(self.last_tok[gidx]), self.cache,
-                jnp.asarray(self.pos[gidx]), jnp.asarray(gidx),
-                jnp.asarray(sidx),
-            )
-            rows = {slot: r for r, slot in enumerate(order)}
-        self._key, sub = jax.random.split(self._key)
-        next_tok = np.asarray(self._sample(logits, sub))
-        for slot in order:
-            r = self.active[slot]
-            tok = int(next_tok[rows[slot]])
-            r.output.append(tok)
-            self.tokens_generated += 1
-            self.pos[slot] += 1
-            self.last_tok[slot] = tok
-            if self._complete(r):
-                self._finish(slot)
+        with TraceAnnotation("engine.decode", bpad=bpad, occupancy=n):
+            if bpad >= self.max_slots:
+                decode = self._program("decode", counter="decode_traces")
+                logits, self.cache = decode(
+                    self.params, jnp.asarray(self.last_tok), self.cache,
+                    jnp.asarray(self.pos),
+                )
+                rows = {slot: slot for slot in order}
+            else:
+                gidx = np.empty(bpad, np.int32)
+                gidx[:n] = order
+                gidx[n:] = order[0]
+                sidx = np.full(bpad, self.max_slots, np.int32)
+                sidx[:n] = order
+                decode = self._program("decodeg", bpad, counter="decode_traces")
+                logits, self.cache = decode(
+                    self.params, jnp.asarray(self.last_tok[gidx]), self.cache,
+                    jnp.asarray(self.pos[gidx]), jnp.asarray(gidx),
+                    jnp.asarray(sidx),
+                )
+                rows = {slot: r for r, slot in enumerate(order)}
+        with TraceAnnotation("engine.sample"):
+            self._key, sub = jax.random.split(self._key)
+            next_tok = np.asarray(self._sample(logits, sub))
+        with TraceAnnotation("engine.retire"):
+            for slot in order:
+                r = self.active[slot]
+                tok = int(next_tok[rows[slot]])
+                r.output.append(tok)
+                self.tokens_generated += 1
+                self.pos[slot] += 1
+                self.last_tok[slot] = tok
+                if self._complete(r):
+                    self._finish(slot)
 
     def step(self) -> None:
         """One engine tick: admit (FIFO), decode one token for all active slots.
@@ -658,13 +674,39 @@ class ServeEngine:
         submit order.  Each loop iteration either admits at least one
         pending request or breaks, so the fixpoint terminates.
         """
+        built = self._built()
+        with TraceAnnotation("engine.step", tick=self._steps) as span:
+            if self._fast:
+                with TraceAnnotation("engine.admit"):
+                    admitted = self._admit_fixpoint()
+            else:
+                admitted = self._admit_fixpoint()
+            decoded = len(self.active)
+            if decoded:
+                if self._fast:
+                    self._decode_active()
+                else:
+                    self._decode_legacy()
+            span.set_metadata(admitted=admitted, decoded=decoded,
+                              built=self._built() - built)
+        self._steps += 1
+
+    def _built(self) -> int:
+        """Programs built so far: the shared cache's (fast path), or this
+        engine's traces (legacy path)."""
+        return PROGRAMS.builds if self._fast else self.prefill_traces + self.decode_traces
+
+    def _admit_fixpoint(self) -> int:
+        """Admit pending requests into free slots until none fits; returns
+        how many were admitted."""
+        admitted = 0
         while True:
             for slot in list(self.active):
                 if self._complete(self.active[slot]):
                     self._finish(slot)
             free = [s for s in range(self.max_slots) if s not in self.active]
             if not (self.pending and free):
-                break
+                return admitted
             if self._fast:
                 pairs = []
                 for slot in free:
@@ -672,60 +714,59 @@ class ServeEngine:
                         break
                     pairs.append((self.pending.popleft(), slot))
                 self._admit_many(pairs)
+                admitted += len(pairs)
             else:
                 for slot in free:
                     if not self.pending:
                         break
                     self._admit(self.pending.popleft(), slot)
+                    admitted += 1
 
-        if self.active:
-            if self._fast:
-                self._decode_active()
-            else:
-                if self._decode is None:
-                    # legacy per-engine decode program (counts retraces at
-                    # trace time like the original engine)
-                    def _expand(path, leaf):
-                        return jnp.expand_dims(leaf, _leaf_axis(path))
+    def _decode_legacy(self) -> None:
+        """One token for every slot of the pool with the per-engine decode
+        program (built lazily; counts retraces at trace time like the
+        original engine)."""
+        if self._decode is None:
+            def _expand(path, leaf):
+                return jnp.expand_dims(leaf, _leaf_axis(path))
 
-                    def _squeeze(path, leaf):
-                        return jax.lax.index_in_dim(
-                            leaf, 0, axis=_leaf_axis(path), keepdims=False
-                        )
-
-                    cfg = self.cfg
-
-                    def decode_one(params, tok, cache_slot, pos):
-                        self.decode_traces += 1  # trace-time side effect
-                        cache_b = jax.tree_util.tree_map_with_path(_expand, cache_slot)
-                        logits, new_cache = T.decode_step(
-                            params, tok[None, None], cache_b, pos, cfg
-                        )
-                        return logits[0, 0], jax.tree_util.tree_map_with_path(
-                            _squeeze, new_cache
-                        )
-
-                    self._decode = jax.jit(jax.vmap(
-                        decode_one, in_axes=(None, 0, self._axes, 0),
-                        out_axes=(0, self._axes),
-                    ))
-                logits, new_cache = self._decode(
-                    self.params, jnp.asarray(self.last_tok), self.cache,
-                    jnp.asarray(self.pos),
+            def _squeeze(path, leaf):
+                return jax.lax.index_in_dim(
+                    leaf, 0, axis=_leaf_axis(path), keepdims=False
                 )
-                self.cache = new_cache
-                self._key, sub = jax.random.split(self._key)
-                next_tok = np.asarray(self._sample(logits, sub))
-                for slot in list(self.active):
-                    r = self.active[slot]
-                    tok = int(next_tok[slot])
-                    r.output.append(tok)
-                    self.tokens_generated += 1
-                    self.pos[slot] += 1
-                    self.last_tok[slot] = tok
-                    if self._complete(r):
-                        self._finish(slot)
-        self._steps += 1
+
+            cfg = self.cfg
+
+            def decode_one(params, tok, cache_slot, pos):
+                self.decode_traces += 1  # trace-time side effect
+                cache_b = jax.tree_util.tree_map_with_path(_expand, cache_slot)
+                logits, new_cache = T.decode_step(
+                    params, tok[None, None], cache_b, pos, cfg
+                )
+                return logits[0, 0], jax.tree_util.tree_map_with_path(
+                    _squeeze, new_cache
+                )
+
+            self._decode = jax.jit(jax.vmap(
+                decode_one, in_axes=(None, 0, self._axes, 0),
+                out_axes=(0, self._axes),
+            ))
+        logits, new_cache = self._decode(
+            self.params, jnp.asarray(self.last_tok), self.cache,
+            jnp.asarray(self.pos),
+        )
+        self.cache = new_cache
+        self._key, sub = jax.random.split(self._key)
+        next_tok = np.asarray(self._sample(logits, sub))
+        for slot in list(self.active):
+            r = self.active[slot]
+            tok = int(next_tok[slot])
+            r.output.append(tok)
+            self.tokens_generated += 1
+            self.pos[slot] += 1
+            self.last_tok[slot] = tok
+            if self._complete(r):
+                self._finish(slot)
 
     def run(self, requests: list[Request], max_ticks: int = 10_000) -> list[Request]:
         """Submit everything and tick until done.  Returns the requests."""

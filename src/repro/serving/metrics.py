@@ -6,11 +6,12 @@ the printed benchmark table, ``benchmarks/check_regression.py --suite S``,
 
 * ``p50_ttft_ticks`` / ``p95_ttft_ticks`` / ``p99_ttft_ticks`` — percentiles
   of time-to-first-token in **engine ticks** (the first token rides the
-  prefill at admit, so TTFT is exactly queue wait; tick-denominated metrics
-  are bit-deterministic given the loadgen seed and gateable across
+  prefill at admit, so TTFT in ticks is exactly queue wait; tick-denominated
+  metrics are bit-deterministic given the loadgen seed and gateable across
   machines);
-* ``p50_ttft_ms`` / ``p99_ttft_ms`` — the same percentiles in wall
-  milliseconds (reported, not gated: host-dependent);
+* ``p50_ttft_ms`` / ``p99_ttft_ms`` — wall milliseconds from submit until
+  the first token reaches the host: queue wait plus the prefill (reported,
+  not gated: host-dependent);
 * ``per_token_ms`` — mean wall milliseconds per generated token over the
   run (decode steps amortized over all tokens);
 * ``tok_per_s`` — aggregate generated tokens per wall second;

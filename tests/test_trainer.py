@@ -135,8 +135,8 @@ def test_adam_adgda_one_liner():
     state = tr.init({"w": jnp.zeros((1,))}, jax.random.PRNGKey(0))
     etas = []
     for _ in range(40):
+        etas.append(float(tr.local.lr(state.opt)))  # the rate this round applies
         state, aux = tr.step(state, batch)
-        etas.append(float(aux["eta_theta"]))
     assert etas[0] == pytest.approx(0.0)  # warmup starts at zero
     assert max(etas) <= 0.3 + 1e-6
     assert np.isfinite(float(aux["worst_loss"]))
