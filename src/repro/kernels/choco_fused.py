@@ -15,7 +15,9 @@ collapse that to ~3 full-tensor passes plus wire-sized (packed) traffic:
   rolled packed payload tile and accumulate ``sum_k w_k * deq(payload_k)``
   directly into the ``s`` update.  Per-neighbor f32 tensors never
   materialize; the per-(shift, node) dequant scales ride alongside as a
-  lane-broadcast row per node.
+  lane-broadcast row per node.  ``s`` is read and written in its own dtype:
+  the kernel upcasts the tile in VMEM, adds the f32 accumulator and casts
+  once on the store.
 
 Both kernels grid over row-blocks only and keep the full node axis inside
 each tile ([m, block, 128]): the stacked node axis is small (nodes) while d
@@ -29,6 +31,14 @@ grows (``_pick_block``).
 fuse into a read-only reduction) so the payload is bit-identical to the
 ``packed=False`` oracle path: the same per-node keys, the same uniform noise,
 the same norm reduction, the same floor/clip arithmetic.
+
+When every shift of the topology fits in one ``SHIFT_BATCH`` mix call (a
+ring, a torus, a small mesh), ``s`` stays in the leaf dtype end to end and no
+f32 copy of it exists in HBM.  With more shifts the mix runs in several calls
+and ``s`` is carried between them in f32, cast to the leaf dtype once at the
+end, so the accumulate-everything-then-cast arithmetic of the oracle holds
+either way.  The mix runs under the named scope of its path,
+``choco.mix.leaf_dtype`` or ``choco.mix.f32_carry`` (op_name metadata only).
 
 The packed payload is rolled along the node axis *outside* the kernels
 (wire-sized traffic only).  Under the production mesh those rolls lower to
@@ -194,24 +204,29 @@ def _fused_mix_kernel(wscale_ref, lvl_ref, sign_ref, s_ref, s_new_ref,
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
 def fused_mix_pallas(rolled_lvl, rolled_sign, s, wscale, bits: int, interpret: bool):
     """rolled_lvl: [K, m, R/pack, 128] u8, rolled_sign: [K, m, R/8, 128] u8,
-    s: [m, R, 128] (leaf dtype), wscale: [K, m] f32 with
-    wscale[k, i] = w_k * deq_scale[(i - shift_k) mod m].
+    s: [m, R, 128] (leaf dtype, or the f32 carry of a multi-call mix),
+    wscale: [K, m] f32 with wscale[k, i] = w_k * deq_scale[(i - shift_k) mod m].
 
-    Returns s_new [m, R, 128]: s + sum_k w_k * deq(rolled payload_k).
+    Returns s_new [m, R, 128] in s.dtype: s + sum_k w_k * deq(rolled
+    payload_k), summed in f32 and cast once, in VMEM.
     """
     K, m, prows, lanes = rolled_lvl.shape
     assert lanes == LANES and K == wscale.shape[0]
     pack = 8 // bits
     rows = prows * pack
     assert s.shape == (m, rows, LANES)
-    # live f32-sized buffers: s and s_new (double-buffered), the f32
-    # accumulator and the in-kernel temporaries, plus per unrolled shift its
-    # unpacked int32 levels and signs and (double-buffered) u8 payload tiles
-    # of (1/pack + 1/8) bytes per element — K-dependent (mesh has K = m
-    # shifts).  Sized from the v5e compiler's scoped-VMEM report at
-    # qwen3-1.7b widths (tests/test_tpu_compile.py).
+    # live buffers in f32 units: s and s_new, double-buffered, in s.dtype
+    # (4 for f32, 2 for bf16), the f32 accumulator and the in-kernel
+    # temporaries (4), plus per unrolled shift its unpacked int32 levels and
+    # signs and (double-buffered) u8 payload tiles of (1/pack + 1/8) bytes per
+    # element — K-dependent (mesh has K = m shifts).  Sized from the v5e
+    # compiler's scoped-VMEM report at qwen3-1.7b widths (4 nodes, 3 shifts,
+    # kq4b: 13.9 units per element with s in f32, 12.0 in bf16, against 14.9
+    # and 12.9 counted here; tests/test_tpu_compile.py).
+    s_f32 = float(s.dtype.itemsize)  # 4 buffers of itemsize / 4 units
     payload_f32 = 2 * K * (1.0 / pack + 0.125) / 4.0
-    block = _pick_block(rows, 8 * pack, m, f32_operands=8.0 + 2.0 * K + payload_f32)
+    block = _pick_block(rows, 8 * pack, m,
+                        f32_operands=s_f32 + 4.0 + 2.0 * K + payload_f32)
     grid = (rows // block,)
     ws = jnp.broadcast_to(wscale[..., None], (K, m, LANES)).astype(jnp.float32)
     return pl.pallas_call(
@@ -239,6 +254,11 @@ def fused_round_leaf(leaf, hat, s, key, shifts: Sequence[tuple[int, float]],
     compressor bit-for-bit on the payload (same keys, noise, norms and
     floor/clip arithmetic); ``s_new`` agrees to f32 rounding (the weighted
     accumulation is reassociated inside the kernel).
+
+    With at most ``SHIFT_BATCH`` shifts the one mix call reads and writes
+    ``s`` in the leaf dtype (scope ``choco.mix.leaf_dtype``); with more, the
+    calls carry ``s`` in f32 and it is cast once at the end (scope
+    ``choco.mix.f32_carry``).  Both compute ``dtype(f32(s) + acc)``.
 
     ``roll_fn(x, shift)`` overrides how the packed payload travels the node
     axis — the SPMD neighbor-exchange backend (core/exchange.py) substitutes
@@ -298,23 +318,27 @@ def fused_round_leaf(leaf, hat, s, key, shifts: Sequence[tuple[int, float]],
         roll0 = lambda x, sh: x if sh == 0 else jnp.roll(x, sh, axis=0)
     else:
         roll0 = lambda x, sh: x if sh == 0 else roll_fn(x, sh)
-    # the accumulator stays f32 across batches (cast to the leaf dtype once
-    # at the end), so multi-batch topologies match the oracle's
+    # one batch: the kernel takes s in the leaf dtype and casts once in VMEM.
+    # Several: the accumulator stays f32 across batches (cast to the leaf
+    # dtype once at the end), so multi-batch topologies match the oracle's
     # accumulate-everything-then-cast semantics for low-precision leaves too
-    s_new_g = grid3(s.reshape(m, -1).astype(jnp.float32))
     shifts = tuple(shifts)
-    for lo in range(0, len(shifts), SHIFT_BATCH):
-        batch = shifts[lo:lo + SHIFT_BATCH]
-        rolled_lvl = jnp.stack([roll0(lvl, sh) for sh, _ in batch])
-        rolled_sign = jnp.stack([roll0(sign, sh) for sh, _ in batch])
-        wscale = jnp.stack(
-            [w * roll0(scale_deq, sh) for sh, w in batch]
-        ).astype(jnp.float32)
-        s_new_g = fused_mix_pallas(
-            rolled_lvl, rolled_sign, s_new_g, wscale, bits, interpret=interpret
-        )
+    one_batch = len(shifts) <= SHIFT_BATCH
+    with jax.named_scope("choco.mix.leaf_dtype" if one_batch else "choco.mix.f32_carry"):
+        s_new_g = grid3(s.reshape(m, -1).astype(s.dtype if one_batch else jnp.float32))
+        for lo in range(0, len(shifts), SHIFT_BATCH):
+            batch = shifts[lo:lo + SHIFT_BATCH]
+            rolled_lvl = jnp.stack([roll0(lvl, sh) for sh, _ in batch])
+            rolled_sign = jnp.stack([roll0(sign, sh) for sh, _ in batch])
+            wscale = jnp.stack(
+                [w * roll0(scale_deq, sh) for sh, w in batch]
+            ).astype(jnp.float32)
+            s_new_g = fused_mix_pallas(
+                rolled_lvl, rolled_sign, s_new_g, wscale, bits, interpret=interpret
+            )
 
     unpad = lambda x: x.reshape(m, -1)[:, :d].reshape((m,) + inner_shape)
+    s_new = unpad(s_new_g).astype(dtype)
     if with_digest:
-        return theta_new, unpad(hat_new_g), unpad(s_new_g).astype(dtype), dig
-    return theta_new, unpad(hat_new_g), unpad(s_new_g).astype(dtype)
+        return theta_new, unpad(hat_new_g), s_new, dig
+    return theta_new, unpad(hat_new_g), s_new

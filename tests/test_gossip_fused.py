@@ -1,5 +1,7 @@
 """Fused CHOCO gossip round: kernel-vs-ref oracles and bit-compatibility of
 the fused choco_round fast path against the packed/unpacked reference paths."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -40,18 +42,129 @@ def test_fused_encode_kernel_matches_ref(bits):
     np.testing.assert_allclose(np.asarray(hat_k), np.asarray(hat_r), atol=1e-6)
 
 
+def _bits16(x):
+    return np.asarray(x).view(np.uint16)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("bits", [8, 4])
-def test_fused_mix_kernel_matches_ref(bits):
+def test_fused_mix_kernel_matches_ref(bits, dtype):
+    """s in f32 (the multi-batch carry) or in the leaf dtype: the kernel
+    returns s.dtype(f32(s) + acc), cast once after the f32 accumulation."""
     m, rows, K = 6, 32, 3
     pack = 8 // bits
     k1, k2, k3, k4 = jax.random.split(KEY, 4)
     lvl = jax.random.randint(k1, (K, m, rows // pack, ref.LANES), 0, 256, jnp.uint8)
     sign = jax.random.randint(k2, (K, m, rows // 8, ref.LANES), 0, 256, jnp.uint8)
-    s = jax.random.normal(k3, (m, rows, ref.LANES))
+    s = jax.random.normal(k3, (m, rows, ref.LANES)).astype(dtype)
     wscale = jax.random.uniform(k4, (K, m), minval=0.0, maxval=0.1)
     out_k = choco_fused.fused_mix_pallas(lvl, sign, s, wscale, bits, interpret=True)
     out_r = ref.fused_mix_ref(lvl, sign, s, wscale, bits)
-    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r), atol=1e-5)
+    assert out_k.dtype == out_r.dtype == dtype
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r), atol=1e-5)
+    else:
+        np.testing.assert_array_equal(_bits16(out_k), _bits16(out_r))
+
+
+# ------------------------------------------- s in the leaf dtype through the mix
+def _staging_mix(real, seen, tag, stage):
+    """``real`` (fused_mix_pallas) recording its payload operands; with
+    ``stage`` it takes s through f32 in HBM around the kernel, as the round
+    used to."""
+
+    def mix(lvl, sign, s_g, wscale, bits, interpret):
+        seen[tag] = (lvl, sign, s_g.dtype)
+        if stage:
+            out = real(lvl, sign, s_g.astype(jnp.float32), wscale, bits, interpret=interpret)
+            return out.astype(s_g.dtype)
+        return real(lvl, sign, s_g, wscale, bits, interpret=interpret)
+
+    return mix
+
+
+@pytest.mark.parametrize("inner", [(32, 128), (151, 64)], ids=["layer-chunk", "padded-chunk"])
+def test_fused_round_bf16_ring_leaf_dtype_bit_identical(monkeypatch, inner):
+    """bf16 ring(2) kq4b leaf, shaped like one scan chunk: with s kept in bf16
+    through the mix, theta_new, hat_new, s_new and the payload are bitwise
+    those of the f32-staged composition."""
+    m, bits = 2, 4
+    ks = jax.random.split(KEY, 4)
+    leaf, hat, s = (jax.random.normal(k, (m,) + inner).astype(jnp.bfloat16) for k in ks[:3])
+    hat, s = 0.5 * hat, 0.1 * s
+    shifts = topology.ring(m).shifts
+    seen, outs, real = {}, {}, choco_fused.fused_mix_pallas
+    for tag, stage in (("leaf_dtype", False), ("f32_staged", True)):
+        monkeypatch.setattr(choco_fused, "fused_mix_pallas", _staging_mix(real, seen, tag, stage))
+        outs[tag] = choco_fused.fused_round_leaf(
+            leaf, hat, s, ks[3], shifts, 0.3, bits, interpret=True)
+    assert seen["leaf_dtype"][2] == jnp.bfloat16
+    for a, b in zip(outs["leaf_dtype"], outs["f32_staged"]):
+        assert a.dtype == b.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(_bits16(a), _bits16(b))
+    for a, b in zip(seen["leaf_dtype"][:2], seen["f32_staged"][:2]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the mix did move s
+    assert not np.array_equal(_bits16(outs["leaf_dtype"][2]), _bits16(s))
+
+
+_SHAPE_OPS = {"reshape", "slice", "squeeze", "pad", "concatenate", "broadcast_in_dim", "copy"}
+
+
+def _follow_s(jaxpr, s_vars, scope, found):
+    """Walk a jaxpr from the vars holding s (through shape-only ops, converts,
+    nested jits and the mix kernel), recording the converts of s, the dtypes
+    of the kernel call that takes it, and every Pallas call with the named
+    scopes around it."""
+    from jax.extend.core import Var
+
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        where = f"{scope}/{eqn.source_info.name_stack}"
+        hit = [isinstance(v, Var) and v in s_vars for v in eqn.invars]
+        if name == "pallas_call":
+            found["pallas"].append(where)
+            if any(hit):
+                found["s_kernel"].append((
+                    [v.aval.dtype for v, h in zip(eqn.invars, hit) if h],
+                    [v.aval.dtype for v in eqn.outvars]))
+                s_vars.update(eqn.outvars)
+        elif "jaxpr" in eqn.params:
+            inner = eqn.params["jaxpr"]
+            inner = getattr(inner, "jaxpr", inner)
+            sub = {iv for iv, h in zip(inner.invars, hit) if h}
+            _follow_s(inner, sub, f"{where}/{eqn.params.get('name', name)}", found)
+            s_vars.update(ov for ov, iv in zip(eqn.outvars, inner.outvars)
+                          if isinstance(iv, Var) and iv in sub)
+        elif any(hit) and name in _SHAPE_OPS | {"convert_element_type"}:
+            if name == "convert_element_type":
+                found["convert"].append((eqn.invars[0].aval.dtype, eqn.outvars[0].aval.dtype))
+            s_vars.update(eqn.outvars)
+
+
+@pytest.mark.parametrize("topo", [topology.ring(2), topology.mesh(16)], ids=["ring2", "mesh16"])
+def test_fused_round_mix_path_engaged(topo):
+    """The traced round of a bf16 leaf: on a ring no f32 convert of s exists
+    outside the kernel and every mix call is scoped ``choco.mix.leaf_dtype``;
+    on mesh(16) (two shift batches) every one is ``choco.mix.f32_carry``."""
+    m, bits = topo.num_nodes, 4
+    x = jax.ShapeDtypeStruct((m, 24, 160), jnp.bfloat16)
+    closed = jax.make_jaxpr(
+        lambda t, h, s, k: choco_fused.fused_round_leaf(
+            t, h, s, k, topo.shifts, 0.3, bits, interpret=True)
+    )(x, x, x, jax.ShapeDtypeStruct((2,), jnp.uint32))
+    found = {"pallas": [], "s_kernel": [], "convert": []}
+    _follow_s(closed.jaxpr, {closed.jaxpr.invars[2]}, "", found)
+    mix = [where for where in found["pallas"] if "/fused_mix_pallas" in where]
+    calls = -(-len(topo.shifts) // choco_fused.SHIFT_BATCH)
+    path = "leaf_dtype" if calls == 1 else "f32_carry"
+    assert [re.findall(r"choco\.mix\.\w+", w) for w in mix] == [[f"choco.mix.{path}"]] * calls
+    if path == "leaf_dtype":
+        assert found["convert"] == []
+        assert found["s_kernel"] == [([jnp.bfloat16], [jnp.bfloat16])]
+    else:  # f32 in before the first call, carried, bf16 out after the last
+        assert found["convert"] == [(jnp.bfloat16, jnp.float32), (jnp.float32, jnp.bfloat16)]
+        assert found["s_kernel"] == [([jnp.float32], [jnp.float32])] * len(mix)
 
 
 # --------------------------------------------- fused choco_round vs the oracles

@@ -16,6 +16,7 @@ compiled for a described chip cannot be read back without one).
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,7 +94,16 @@ def test_fused_choco_round_compile(one_chip, m):
         lambda t, h, s, k: fused_round_leaf(t, h, s, k, shifts, 0.5, 4, interpret=False),
         leaf, leaf, leaf, _key(one_chip),
     )
-    assert compiled.as_text().count("tpu_custom_call") >= 2  # encode and mix
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2  # encode and mix
+    # one shift batch: the mix reads and writes s in bf16, no f32 copy of s
+    mix = [ln for ln in text.splitlines()
+           if "tpu_custom_call" in ln and "fused_mix_pallas" in ln and "custom-call(" in ln]
+    assert len(mix) == 1
+    result = re.match(r"\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\w+)\[", mix[0]).group(1)
+    operands = re.search(r"operand_layout_constraints=\{(.*?\})\}", mix[0]).group(1)
+    s_operand = re.findall(r"(\w+)\[[\d,]*\]", operands)[-1]  # (wscale, lvl, sign, s)
+    assert (result, s_operand) == ("bf16", "bf16")
 
 
 def test_packed_choco_round_compile(one_chip, monkeypatch):
